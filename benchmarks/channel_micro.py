@@ -24,7 +24,7 @@
     The CI gate tracks the within-run ref/masked ratio at r8192/r32768
     (check_bench --normalize-impl masked) so the shared-grouping serve
     cannot silently lose its scaling edge; the kernel's scaling numbers
-    come from the accelerator lane (benchmarks/kernel_sweep.py).
+    come from benchmarks/kernel_sweep.py on a TPU.
   * combine: pre-wire request combining (DESIGN.md §13) over a Zipf skew
     sweep plus the 16-key conflict-heavy mix, combine{off,ref} under a
     pressured defer drain — the combine mode rides the pack_impl column
@@ -221,7 +221,7 @@ def serve_scale(csv, mesh, args):
         impls = ["masked", "ref"]
         # interpret-mode Pallas executes the grid in Python-built XLA loops:
         # honest on semantics, useless on wall-clock past a few 10k rows —
-        # the uninterpreted sweep lives in the accelerator lane
+        # the uninterpreted sweep is benchmarks/kernel_sweep.py on a TPU
         if r <= args.scale_pallas_max_r:
             impls.append("pallas")
         for impl in impls:
@@ -475,4 +475,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.runtime import enable_compile_cache
+    enable_compile_cache()
     main()

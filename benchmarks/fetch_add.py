@@ -130,4 +130,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.runtime import enable_compile_cache
+    enable_compile_cache()
     main()

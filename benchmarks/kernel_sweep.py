@@ -1,15 +1,15 @@
-"""Uninterpreted kernel sweeps — the accelerator CI lane.
+"""Uninterpreted kernel sweeps on a TPU.
 
 Runs the tiled delegation serve/pack Pallas kernels UNINTERPRETED (real
 Mosaic lowering) over row-batch and block-size sweeps, printing us/round
 and achieved bytes/s next to the closed-form roofline
 (repro.launch.rooflines.delegation_serve_roofline).
 
-On a CPU-only host there is nothing honest to measure — interpret-mode
-wall-clock is Python, not kernel, time — so the script SKIPS (exit 0)
-unless a TPU backend is present.  The CPU CI lane covers semantics
-(interpret-mode bit-identity, tests/test_tiled_kernels.py); this lane
-covers performance, dispatched manually via .github/workflows/accel.yml.
+On a host without a TPU there is nothing honest to measure — interpret-
+mode wall-clock is Python, not kernel, time — so the script fails (exit 2)
+there.  The CPU tests cover semantics (interpret-mode bit-identity,
+tests/test_tiled_kernels.py) and compilation for a described chip
+(tests/test_tpu_compile.py).
 """
 from __future__ import annotations
 
@@ -34,9 +34,8 @@ def main(argv=None):
     import jax
     if jax.default_backend() != "tpu":
         print(f"kernel_sweep: backend is {jax.default_backend()!r}, not "
-              f"tpu — skipping (uninterpreted Pallas needs hardware; the "
-              f"CPU lane validates semantics in interpret mode)")
-        return 0
+              f"tpu — uninterpreted Pallas needs the chip", file=sys.stderr)
+        return 2
 
     import jax.numpy as jnp
     from repro.kernels import ops as kops
@@ -88,4 +87,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.runtime import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

@@ -1,8 +1,12 @@
 """Benchmark harness — one function per paper table/figure.
 
-Each benchmark runs in a subprocess with 8 simulated devices (the parent
-stays single-device per the dry-run protocol) in a reduced-size mode so the
-full suite completes on CPU; pass --full for the paper-scale sweeps.
+Each benchmark runs in a child process of its own, in a reduced-size mode
+so the suite completes on the CPU; pass --full for the paper-scale sweeps.
+On the CPU a child sees 8 simulated devices (``run_in_subprocess`` forces
+the host device count); on a TPU host it sees the real chips.  A chip
+belongs to one process at a time, so this parent process does not import
+JAX until every child has exited (the roofline table at the end is the
+first thing here that touches it).
 Prints ``name,us_per_call,derived`` CSV summary lines at the end.
 """
 from __future__ import annotations

@@ -10,27 +10,28 @@ and an (N, N) same-segment mask — O(N²) work and VMEM that capped the row
 batch at a few thousand), the serve is now FOUR small multi-block grid
 kernels composed by one jitted wrapper.  No (N, N) or (K, N) intermediate
 ever materializes: every mask/one-hot lives at BLOCK granularity —
-(br, br) same-segment masks and (br, bk) key one-hots — and the table
-streams through key-partitioned (bk, W) tiles:
+(br, br) same-segment masks and (bk, br) / (br, bk) key one-hots — and
+the table streams through key-partitioned (bk, W) tiles:
 
   phase snapshots   T0 --PUT--> T1 --ADD--> T2 --CAS--> T3
 
   1. ``_scatter_last`` (PUT, then CAS commit): grid (key tiles, row tiles)
      with rows INNERMOST.  Each step picks the block-local last-OK row per
-     segment ((br, br) masked matmul) and overwrites its table line via a
-     (br, bk) one-hot transpose matmul; later row tiles overwrite earlier
+     segment ((br, br) masked sum) and overwrites its table line via a
+     (bk, br) one-hot matmul; later row tiles overwrite earlier
      ones, so the sequential row walk realizes global last-writer-wins
      exactly (sorted segments keep request order inside a tile and across
      tiles).
   2. ``_scatter_add`` (ADD totals): same grid; deltas accumulate into the
-     key tile via the masked one-hot transpose matmul.
+     key tile via the masked (bk, br) one-hot matmul.
   3. ``_gather`` (all read lanes + ADD priors): grid (row tiles, key
      tiles) with KEYS innermost.  Each row block computes its ADD
      exclusive-prefix priors block-locally ((br, br) strict-lower same-
      segment matmul) plus a CARRY — a VMEM running delta-sum for the one
      segment that can straddle a row-tile boundary, keyed by the
      Grouping's per-tile metadata (``cont``: does tile t continue tile
-     t-1's last segment?).  The key-tile walk then accumulates the GET
+     t-1's last segment?), read from SMEM.  The key-tile walk then
+     accumulates the GET
      (from T0), ADD-base (from T1) and CAS-current (from T2) gathers.
   4. CAS compare (``cur == expect``) runs as plain jnp between the calls
      (exact — no kernel needed), and the commit reuses ``_scatter_last``.
@@ -51,6 +52,14 @@ by key tile) iterate rows innermost — hence separate pallas_calls per
 phase, with the table snapshots threaded between them by XLA.  The cost is
 three extra table copies (T1/T2/T3) vs the old in-place update; the win is
 row batches bounded by HBM, not by one VMEM-resident (N, N) mask.
+
+Layout discipline (what the chip's Mosaic compiler accepts): a per-row
+vector never changes orientation inside a kernel — Mosaic cannot lower a
+(br,) -> (br, 1) relayout — so the wrapper hands each kernel every
+per-row vector as a (1, N) row where it broadcasts along lanes and as an
+(N, 1) column where it broadcasts along sublanes.  Matmuls that move
+table or payload values run at ``Precision.HIGHEST``: a one-hot gather of
+an f32 value is exact only without a bf16 pass.
 """
 from __future__ import annotations
 
@@ -80,37 +89,41 @@ def num_row_tiles(n: int, br: int) -> int:
     return -(-n // b)
 
 
-def _scatter_last_kernel(tin_ref, keys_ref, sid_ref, ok_ref, value_ref,
-                         out_ref, *, br: int, bk: int):
+_HI = jax.lax.Precision.HIGHEST
+_f = lambda b: b.astype(jnp.float32)
+
+
+def _scatter_last_kernel(tin_ref, keys_ref, sid_ref, sidc_ref, ok_ref, okc_ref,
+                         value_ref, out_ref, *, br: int, bk: int):
     """One (key tile, row tile) step of last-writer-wins commit.
 
     ``ok`` flags the candidate rows (one lane per call, so same key <=>
     same segment and each key has at most one block-local winner); the
     block-local winner is the last OK row of its segment, and later row
     tiles overwrite earlier ones — global last-writer without any
-    cross-tile state."""
+    cross-tile state.  Per-row inputs come as (1, br) rows; ``sidc``/
+    ``okc`` are the same vectors as (br, 1) columns."""
     kt, rt = pl.program_id(0), pl.program_id(1)
 
     @pl.when(rt == 0)
     def _init():
         out_ref[...] = tin_ref[...]
 
-    f = lambda b: b.astype(jnp.float32)
-    keys = keys_ref[0]                                      # (br,) int32
-    sid = sid_ref[0]                                        # (br,) int32
-    ok = ok_ref[0] > 0                                      # (br,) bool
-    pos = jax.lax.broadcasted_iota(jnp.int32, (br, 1), 0)[:, 0]
-    sameseg = sid[:, None] == sid[None, :]                  # (br, br)
-    earlier = pos[:, None] > pos[None, :]
-    later_ok = jnp.dot(f(earlier & sameseg).T, f(ok)[:, None],
-                       preferred_element_type=jnp.float32)[:, 0]
+    ok = ok_ref[...] > 0                                    # (1, br)
+    # later[j, i]: row j comes after row i in the same segment
+    later = ((jax.lax.broadcasted_iota(jnp.int32, (br, br), 0) >
+              jax.lax.broadcasted_iota(jnp.int32, (br, br), 1)) &
+             (sidc_ref[...] == sid_ref[...]))
+    later_ok = jnp.sum(_f(later) * _f(okc_ref[...] > 0), axis=0,
+                       keepdims=True)                       # (1, br)
     win = ok & (later_ok == 0.0)
-    kh = keys - kt * bk                                     # tile-local key
-    oh_w = f((kh[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (br, bk), 1)) & win[:, None])            # (br, bk)
-    wrote = jnp.sum(oh_w, axis=0)                           # (bk,) 0/1
-    out_ref[...] = out_ref[...] * (1.0 - wrote)[:, None] + \
-        jnp.dot(oh_w.T, value_ref[...], preferred_element_type=jnp.float32)
+    kh = keys_ref[...] - kt * bk                            # tile-local key
+    oh_w = _f((jax.lax.broadcasted_iota(jnp.int32, (bk, br), 0) == kh)
+              & win)                                        # (bk, br)
+    wrote = jnp.sum(oh_w, axis=1, keepdims=True)            # (bk, 1) 0/1
+    out_ref[...] = out_ref[...] * (1.0 - wrote) + \
+        jnp.dot(oh_w, value_ref[...], precision=_HI,
+                preferred_element_type=jnp.float32)
 
 
 def _scatter_add_kernel(tin_ref, keys_ref, lane_ref, value_ref, out_ref, *,
@@ -122,105 +135,96 @@ def _scatter_add_kernel(tin_ref, keys_ref, lane_ref, value_ref, out_ref, *,
     def _init():
         out_ref[...] = tin_ref[...]
 
-    m_add = lane_ref[0] == 2
-    kh = keys_ref[0] - kt * bk
-    oh = ((kh[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (br, bk), 1)) & m_add[:, None]).astype(jnp.float32)
-    out_ref[...] += jnp.dot(oh.T, value_ref[...],
+    kh = keys_ref[...] - kt * bk                            # (1, br)
+    oh = _f((jax.lax.broadcasted_iota(jnp.int32, (bk, br), 0) == kh)
+            & (lane_ref[...] == 2))                         # (bk, br)
+    out_ref[...] += jnp.dot(oh, value_ref[...], precision=_HI,
                             preferred_element_type=jnp.float32)
 
 
-def _gather_kernel(t0_ref, t1_ref, t2_ref, keys_ref, lane_ref, sid_ref,
-                   value_ref, cont_ref, resp_ref, carry_ref, *,
+def _gather_kernel(meta_ref, t0_ref, t1_ref, t2_ref, keys_ref, lane_ref,
+                   sidc_ref, sid_ref, value_ref, resp_ref, carry_ref, *,
                    br: int, bk: int):
     """One (row tile, key tile) step of the response gather.
 
     At the first key step of each row tile the block computes its ADD
     priors: block-local strict-lower same-segment prefix plus the carried
-    delta sum of the segment straddling the tile boundary (``cont`` from
-    Grouping.tile_meta says whether this tile's leading run continues the
-    previous tile's trailing segment; the carry scratch persists across
-    the whole grid because row tiles advance outermost)."""
+    delta sum of the segment straddling the tile boundary.  ``meta_ref``
+    (SMEM, (3, n_row_tiles)) holds each tile's ``cont`` flag (from
+    Grouping.tile_meta: does this tile's leading run continue the previous
+    tile's trailing segment?) and its first and last segment ids; the
+    carry scratch persists across the whole grid because row tiles advance
+    outermost.  Per-row inputs come as (br, 1) columns, except ``sid``,
+    which the same-segment mask also needs as a (1, br) row."""
     rt, kt = pl.program_id(0), pl.program_id(1)
-    f = lambda b: b.astype(jnp.float32)
-    keys = keys_ref[0]
-    lane = lane_ref[0]
-    sid = sid_ref[0]
+    keys = keys_ref[...]                                    # (br, 1)
+    lane = lane_ref[...]
     m_get, m_add, m_cas = lane == 0, lane == 2, lane == 3
 
     @pl.when(kt == 0)
     def _prior():
-        delta = value_ref[...] * f(m_add)[:, None]          # (br, W)
-        pos = jax.lax.broadcasted_iota(jnp.int32, (br, 1), 0)[:, 0]
-        sameseg = sid[:, None] == sid[None, :]              # (br, br)
-        earlier = pos[:, None] > pos[None, :]
-        prior = jnp.dot(f(earlier & sameseg), delta,
+        sidc = sidc_ref[...]                                # (br, 1)
+        delta = value_ref[...] * _f(m_add)                  # (br, W)
+        earlier = ((jax.lax.broadcasted_iota(jnp.int32, (br, br), 0) >
+                    jax.lax.broadcasted_iota(jnp.int32, (br, br), 1)) &
+                   (sidc == sid_ref[...]))
+        prior = jnp.dot(_f(earlier), delta, precision=_HI,
                         preferred_element_type=jnp.float32)
-        cont = cont_ref[0, 0] > 0
-        sid_first, sid_last = sid_ref[0, 0], sid_ref[0, br - 1]
+        cont = meta_ref[0, rt] > 0
+        sid_first, sid_last = meta_ref[1, rt], meta_ref[2, rt]
         # select, don't multiply: the scratch is UNINITIALIZED before the
         # first carrying tile (NaN/garbage), and 0 * NaN is NaN
-        carry = jnp.where(cont, carry_ref[0], 0.0)          # (W,)
+        carry = jnp.where(cont, carry_ref[...], 0.0)        # (1, W)
         # sorted segment ids are monotone, so rows continuing the previous
         # tile's segment are exactly the leading sid_first run
-        from_carry = f((sid == sid_first) & cont)
-        resp_ref[...] = (prior + from_carry[:, None] * carry[None, :]) * \
-            f(m_add)[:, None]
+        from_carry = _f((sidc == sid_first) & cont)         # (br, 1)
+        resp_ref[...] = (prior + from_carry * carry) * _f(m_add)
         # roll the carry forward: the trailing segment's in-tile delta sum,
         # plus the old carry when ONE segment spans the whole tile
-        in_last = f(sid == sid_last)
-        carry_ref[0] = jnp.sum(delta * in_last[:, None], axis=0) + \
-            f((sid_last == sid_first) & cont) * carry
+        in_last = _f(sidc == sid_last)
+        carry_ref[...] = jnp.sum(delta * in_last, axis=0, keepdims=True) + \
+            jnp.where((sid_last == sid_first) & cont, 1.0, 0.0) * carry
 
-    kh = keys - kt * bk
-    oh = kh[:, None] == jax.lax.broadcasted_iota(jnp.int32, (br, bk), 1)
-    resp_ref[...] += (
-        jnp.dot(f(oh & m_get[:, None]), t0_ref[...],
-                preferred_element_type=jnp.float32) +
-        jnp.dot(f(oh & m_add[:, None]), t1_ref[...],
-                preferred_element_type=jnp.float32) +
-        jnp.dot(f(oh & m_cas[:, None]), t2_ref[...],
-                preferred_element_type=jnp.float32))
+    kh = keys - kt * bk                                     # tile-local key
+    oh = kh == jax.lax.broadcasted_iota(jnp.int32, (br, bk), 1)
+    gather = lambda m, t_ref: jnp.dot(_f(oh & m), t_ref[...], precision=_HI,
+                                      preferred_element_type=jnp.float32)
+    resp_ref[...] += (gather(m_get, t0_ref) + gather(m_add, t1_ref) +
+                      gather(m_cas, t2_ref))
 
 
-def _row_spec(n_rt_axis):
-    """(1, br) row-vector blocks indexed by the row-tile grid axis."""
-    if n_rt_axis == 0:
-        return pl.BlockSpec((1, None), lambda rt, kt: (0, rt))
-    return pl.BlockSpec((1, None), lambda kt, rt: (0, rt))
-
-
-def _scatter_last(table, keys, sid, ok, value, *, br, bk, interpret):
+def _scatter_last(table, keys, sid, sidc, ok, okc, value, *, br, bk,
+                  interpret):
     kp, wp = table.shape
     np_ = value.shape[0]
     n_kt, n_rt = kp // bk, np_ // br
+    row = pl.BlockSpec((1, br), lambda kt, rt: (0, rt))
+    col = pl.BlockSpec((br, 1), lambda kt, rt: (rt, 0))
     return pl.pallas_call(
         functools.partial(_scatter_last_kernel, br=br, bk=bk),
         grid=(n_kt, n_rt),
         in_specs=[
             pl.BlockSpec((bk, wp), lambda kt, rt: (kt, 0)),
-            pl.BlockSpec((1, br), lambda kt, rt: (0, rt)),
-            pl.BlockSpec((1, br), lambda kt, rt: (0, rt)),
-            pl.BlockSpec((1, br), lambda kt, rt: (0, rt)),
+            row, row, col, row, col,
             pl.BlockSpec((br, wp), lambda kt, rt: (rt, 0)),
         ],
         out_specs=pl.BlockSpec((bk, wp), lambda kt, rt: (kt, 0)),
         out_shape=jax.ShapeDtypeStruct((kp, wp), jnp.float32),
         interpret=interpret,
-    )(table, keys, sid, ok, value)
+    )(table, keys, sid, sidc, ok, okc, value)
 
 
 def _scatter_add(table, keys, lane, value, *, br, bk, interpret):
     kp, wp = table.shape
     np_ = value.shape[0]
     n_kt, n_rt = kp // bk, np_ // br
+    row = pl.BlockSpec((1, br), lambda kt, rt: (0, rt))
     return pl.pallas_call(
         functools.partial(_scatter_add_kernel, br=br, bk=bk),
         grid=(n_kt, n_rt),
         in_specs=[
             pl.BlockSpec((bk, wp), lambda kt, rt: (kt, 0)),
-            pl.BlockSpec((1, br), lambda kt, rt: (0, rt)),
-            pl.BlockSpec((1, br), lambda kt, rt: (0, rt)),
+            row, row,
             pl.BlockSpec((br, wp), lambda kt, rt: (rt, 0)),
         ],
         out_specs=pl.BlockSpec((bk, wp), lambda kt, rt: (kt, 0)),
@@ -229,25 +233,27 @@ def _scatter_add(table, keys, lane, value, *, br, bk, interpret):
     )(table, keys, lane, value)
 
 
-def _gather(t0, t1, t2, keys, lane, sid, value, cont, *, br, bk, interpret):
+def _gather(meta, t0, t1, t2, keys, lane, sidc, sid, value, *, br, bk,
+            interpret):
     kp, wp = t0.shape
     np_ = value.shape[0]
     n_kt, n_rt = kp // bk, np_ // br
     tbl = pl.BlockSpec((bk, wp), lambda rt, kt: (kt, 0))
-    row = pl.BlockSpec((1, br), lambda rt, kt: (0, rt))
+    col = pl.BlockSpec((br, 1), lambda rt, kt: (rt, 0))
     return pl.pallas_call(
         functools.partial(_gather_kernel, br=br, bk=bk),
         grid=(n_rt, n_kt),
         in_specs=[
-            tbl, tbl, tbl, row, row, row,
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            tbl, tbl, tbl, col, col, col,
+            pl.BlockSpec((1, br), lambda rt, kt: (0, rt)),
             pl.BlockSpec((br, wp), lambda rt, kt: (rt, 0)),
-            pl.BlockSpec((1, 1), lambda rt, kt: (0, rt)),
         ],
         out_specs=pl.BlockSpec((br, wp), lambda rt, kt: (rt, 0)),
         out_shape=jax.ShapeDtypeStruct((np_, wp), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, wp), jnp.float32)],
         interpret=interpret,
-    )(t0, t1, t2, keys, lane, sid, value, cont)
+    )(meta, t0, t1, t2, keys, lane, sidc, sid, value)
 
 
 @functools.partial(jax.jit, static_argnames=("br", "bk", "interpret"))
@@ -302,19 +308,27 @@ def delegation_serve(table: jax.Array, keys: jax.Array, lane: jax.Array,
     sid_p = jnp.pad(sid, (0, rpad), constant_values=-1)
     value_p = jnp.pad(value.astype(jnp.float32), ((0, rpad), (0, wp - w)))
     expect_p = jnp.pad(expect.astype(jnp.float32), ((0, rpad), (0, wp - w)))
+    # each kernel takes a per-row vector in the orientation it consumes:
+    # (1, N) rows broadcast along lanes, (N, 1) columns along sublanes
     row = lambda x: x.reshape(1, np_)
+    col = lambda x: x.reshape(np_, 1)
     kw = dict(br=br, bk=bk, interpret=interpret)
+    # per-row-tile scalars for the gather's carry, read from SMEM
+    tiles = sid_p.reshape(n_rt, br)
+    meta = jnp.stack([cont.astype(jnp.int32), tiles[:, 0], tiles[:, -1]])
 
     # PUT: every lane-1 row is a candidate; the last per segment commits
-    t1 = _scatter_last(t0, row(keys_p), row(sid_p),
-                       row((lane_p == 1).astype(jnp.int32)), value_p, **kw)
+    put = (lane_p == 1).astype(jnp.int32)
+    t1 = _scatter_last(t0, row(keys_p), row(sid_p), col(sid_p), row(put),
+                       col(put), value_p, **kw)
     # ADD totals
     t2 = _scatter_add(t1, row(keys_p), row(lane_p), value_p, **kw)
     # responses: GET from T0, ADD base (from T1) + priors, CAS cur from T2
-    resp = _gather(t0, t1, t2, row(keys_p), row(lane_p), row(sid_p),
-                   value_p, cont.astype(jnp.int32).reshape(1, n_rt), **kw)
+    resp = _gather(meta, t0, t1, t2, col(keys_p), col(lane_p), col(sid_p),
+                   row(sid_p), value_p, **kw)
     # CAS compare is a plain elementwise reduce — exact outside the kernel
     ok_cas = (lane_p == 3) & jnp.all(resp == expect_p, axis=-1)
-    t3 = _scatter_last(t2, row(keys_p), row(sid_p),
-                       row(ok_cas.astype(jnp.int32)), value_p, **kw)
+    cas = ok_cas.astype(jnp.int32)
+    t3 = _scatter_last(t2, row(keys_p), row(sid_p), col(sid_p), row(cas),
+                       col(cas), value_p, **kw)
     return t3[:k, :w], resp[:n, :w], ok_cas[:n].astype(jnp.float32)

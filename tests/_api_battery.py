@@ -31,6 +31,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
+from _jaxpr import count_primitive
+
 RESULTS = {}
 
 
@@ -178,28 +180,7 @@ def _dedicated():
 # ---------------------------------------------------------------------------
 
 def count_all_to_all(fn, args) -> int:
-    jaxpr = jax.make_jaxpr(fn)(*args)
-
-    def count(j):
-        n = 0
-        for eqn in j.eqns:
-            if eqn.primitive.name == "all_to_all":
-                n += 1
-            for v in eqn.params.values():
-                n += count_in(v)
-        return n
-
-    def count_in(v):
-        import jax.core as jc
-        if isinstance(v, jc.ClosedJaxpr):
-            return count(v.jaxpr)
-        if isinstance(v, jc.Jaxpr):
-            return count(v)
-        if isinstance(v, (list, tuple)):
-            return sum(count_in(x) for x in v)
-        return 0
-
-    return count(jaxpr.jaxpr)
+    return count_primitive(jax.make_jaxpr(fn)(*args), "all_to_all")
 
 
 @check("typed_solo_same_collectives_as_legacy")

@@ -34,6 +34,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
+from _jaxpr import count_primitive
+
 RESULTS = {}
 
 
@@ -214,28 +216,7 @@ def _jaxpr():
     ses = run_pair({"local_shortcut": False, "overflow": "drop"},
                    seeds=(18, 19))
     fn, args = ses.last_exec
-    jaxpr = jax.make_jaxpr(fn)(*args)
-
-    def count(j):
-        n = 0
-        for eqn in j.eqns:
-            if eqn.primitive.name == "all_to_all":
-                n += 1
-            for v in eqn.params.values():
-                n += count_in(v)
-        return n
-
-    def count_in(v):
-        import jax.core as jc
-        if isinstance(v, jc.ClosedJaxpr):
-            return count(v.jaxpr)
-        if isinstance(v, jc.Jaxpr):
-            return count(v)
-        if isinstance(v, (list, tuple)):
-            return sum(count_in(x) for x in v)
-        return 0
-
-    n = count(jaxpr.jaxpr)
+    n = count_primitive(jax.make_jaxpr(fn)(*args), "all_to_all")
     assert n == 2, f"expected 1 request all_to_all + 1 response " \
                    f"transpose, found {n} all_to_all eqns"
 

@@ -13,8 +13,6 @@ plus the structural claims the refactor makes: multi-block grids actually
 engage for R > block size, and no (N, N) / (N, K) dense intermediate
 appears anywhere in the lowered jaxpr.
 """
-import re
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -30,6 +28,8 @@ from repro.core import Received, make_grouping, make_kv_ops, serve_optable
 from repro.core.channel import ChannelConfig, collect_impl_events
 from repro.kernels.delegation_serve import num_row_tiles, row_block
 from repro.kernels import ops as kops
+
+from _jaxpr import iter_eqns
 
 
 # ---------------------------------------------------------------------------
@@ -232,18 +232,10 @@ def test_tile_meta_distinct_keys_never_continue():
 # structural claims: tiled grids engage, no dense intermediates
 # ---------------------------------------------------------------------------
 
-def _walk_avals(jaxpr, acc):
-    for eqn in jaxpr.eqns:
-        for v in list(eqn.invars) + list(eqn.outvars):
-            av = getattr(v, "aval", None)
-            if av is not None and hasattr(av, "shape"):
-                acc.add(tuple(av.shape))
-        for pv in eqn.params.values():
-            if isinstance(pv, jax.core.ClosedJaxpr):
-                _walk_avals(pv.jaxpr, acc)
-            elif isinstance(pv, jax.core.Jaxpr):
-                _walk_avals(pv, acc)
-    return acc
+def _avals(jaxpr):
+    return {tuple(v.aval.shape) for eqn in iter_eqns(jaxpr)
+            for v in list(eqn.invars) + list(eqn.outvars)
+            if hasattr(getattr(v, "aval", None), "shape")}
 
 
 def test_no_dense_intermediates_and_grid_engages():
@@ -260,14 +252,13 @@ def test_no_dense_intermediates_and_grid_engages():
     jx = jax.make_jaxpr(
         lambda *a: delegation_serve(*a, br=256, bk=128, interpret=True))(
         *args)
-    text = str(jx)
-    grids = re.findall(r"grid=\(([\d, ]+)\)", text)
+    grids = [eqn.params["grid_mapping"].grid for eqn in iter_eqns(jx)
+             if eqn.primitive.name == "pallas_call"]
     assert len(grids) == 4, f"expected 4 tiled pallas_calls, saw {grids}"
-    for gspec in grids:
-        dims = [int(x) for x in gspec.split(",") if x.strip()]
-        assert len(dims) == 2 and all(d > 1 for d in dims), \
-            f"tiled grid must engage for R > block size, got grid=({gspec})"
-    acc = _walk_avals(jx.jaxpr, set())
+    for grid in grids:
+        assert len(grid) == 2 and all(d > 1 for d in grid), \
+            f"tiled grid must engage for R > block size, got grid={grid}"
+    acc = _avals(jx)
     # forbidden: any aval coupling the FULL row batch to the full row batch
     # or the full key space — (N, N) masks, (N, K)/(K, N) one-hots.  Block-
     # granularity (br, br)/(br, bk) masks and (N, W) row payloads survive.
