@@ -1,0 +1,9 @@
+"""idle_share: share of the traced window in which no op ran on the
+device, averaged over the chips."""
+
+
+def read(run):
+    t = run.trace
+    if t is None or t.window_s <= 0:
+        return None
+    return (1.0 - t.busy_s / t.window_s) * 100.0
