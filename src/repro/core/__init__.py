@@ -14,6 +14,7 @@
 # nested.py    launch()/nested delegation (chained channel rounds)
 # routing.py   key -> trustee routers + workload generators
 # meshctx.py   current-mesh + current-session threading for shard_map islands
+# tracing.py   the runtime's host spans and device scopes (profiler trace)
 from .opspec import Field, ListField, OpSpec, SchemaError, TrustSchema
 from .channel import (ChannelConfig, ChannelInfo, DelegatedOp,
                       DelegationFuture, Grouping, Packed, Received,

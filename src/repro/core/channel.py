@@ -33,6 +33,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from . import tracing
+
 Pytree = Any
 
 
@@ -1005,48 +1007,55 @@ def delegate(state: Pytree, dst: jax.Array, payload: Pytree, serve_fn: ServeFn,
     r = dst.shape[0]
     n_slots = cfg.n_slots(n_trustees)
     n_bins = n_slots * cfg.n_lanes
-    dst = _to_device_slots(dst, n_trustees, cfg)
-    local_recv = local_mask = None
-    if cfg.local_shortcut and cfg.mode != "dedicated":
-        dst, local_recv, local_mask = _split_local(dst, payload, cfg.axis,
-                                                   cfg.n_lanes)
-        if n_slots == 1:
-            with collect_impl_events() as impl_events:
-                new_state, local_resp = serve_fn(state, local_recv)
-            info = ChannelInfo(jnp.zeros((n_bins,), jnp.int32),
-                               jnp.zeros((r,), bool), 0,
-                               impl_fallback=len(impl_events))
-            return new_state, local_resp, info
+    # device scopes (tracing.py): the client's side up to the wire is
+    # trust.pack (the shortcut split and the combine pass with it)
+    with tracing.scope(tracing.PACK):
+        dst = _to_device_slots(dst, n_trustees, cfg)
+        local_recv = local_mask = None
+        if cfg.local_shortcut and cfg.mode != "dedicated":
+            dst, local_recv, local_mask = _split_local(dst, payload, cfg.axis,
+                                                       cfg.n_lanes)
+    if local_recv is not None and n_slots == 1:
+        with collect_impl_events() as impl_events, \
+                tracing.scope(tracing.SERVE):
+            new_state, local_resp = serve_fn(state, local_recv)
+        info = ChannelInfo(jnp.zeros((n_bins,), jnp.int32),
+                           jnp.zeros((r,), bool), 0,
+                           impl_fallback=len(impl_events))
+        return new_state, local_resp, info
 
-    cctx = None
-    if combine is not None and combine_span is not None \
-            and cfg.combine_impl != "off":
-        # combine AFTER the shortcut split (only wire rows collapse; the
-        # serve still sees shortcut rows individually, appended last, in
-        # exactly the combine-off order) and BEFORE pack (group_sizes — the
-        # planner's demand — count combined rows).  local_recv captured the
-        # pre-combine payload, so shortcut rows serve their original deltas.
-        dst, payload, cctx = combine.pre(dst, payload, combine_span)
-
-    packed, group_sizes = pack(dst, payload, n_bins, cfg)
-    received = transmit(packed, n_bins, cfg)
+    with tracing.scope(tracing.PACK):
+        cctx = None
+        if combine is not None and combine_span is not None \
+                and cfg.combine_impl != "off":
+            # combine AFTER the shortcut split (only wire rows collapse; the
+            # serve still sees shortcut rows individually, appended last, in
+            # exactly the combine-off order) and BEFORE pack (group_sizes —
+            # the planner's demand — count combined rows).  local_recv
+            # captured the pre-combine payload, so shortcut rows serve their
+            # original deltas.
+            dst, payload, cctx = combine.pre(dst, payload, combine_span)
+        packed, group_sizes = pack(dst, payload, n_bins, cfg)
+    with tracing.scope(tracing.TRANSMIT):
+        received = transmit(packed, n_bins, cfg)
     n_chan = received.valid.shape[0]
-    if local_recv is not None:
-        received = _concat_received(received, local_recv)
-    with collect_impl_events() as impl_events:
+    with collect_impl_events() as impl_events, tracing.scope(tracing.SERVE):
+        if local_recv is not None:
+            received = _concat_received(received, local_recv)
         new_state, resp_rows = serve_fn(state, received)
-    local_resp = None
-    if local_recv is not None:
-        local_resp = jax.tree.map(lambda l: l[n_chan:], resp_rows)
-        resp_rows = jax.tree.map(lambda l: l[:n_chan], resp_rows)
-    responses = _respond_unpack(resp_rows, packed.request_slot, n_bins, cfg,
-                                local_resp, local_mask)
-    dropped = packed.dropped
-    rows_combined = req_bytes_saved = 0
-    if cctx is not None:
-        responses, dropped = combine.post(responses, dropped, cctx)
-        rows_combined = lax.psum(
-            jnp.sum(cctx.combined, dtype=jnp.int32), cfg.axis)
+    with tracing.scope(tracing.RESPOND):
+        local_resp = None
+        if local_recv is not None:
+            local_resp = jax.tree.map(lambda l: l[n_chan:], resp_rows)
+            resp_rows = jax.tree.map(lambda l: l[:n_chan], resp_rows)
+        responses = _respond_unpack(resp_rows, packed.request_slot, n_bins,
+                                    cfg, local_resp, local_mask)
+        dropped = packed.dropped
+        rows_combined = req_bytes_saved = 0
+        if cctx is not None:
+            responses, dropped = combine.post(responses, dropped, cctx)
+            rows_combined = lax.psum(
+                jnp.sum(cctx.combined, dtype=jnp.int32), cfg.axis)
         req_bytes_saved = rows_combined * _req_bytes_per_row(payload,
                                                              cfg.wire_fmt)
     n_rows = n_bins * cfg.total_capacity()

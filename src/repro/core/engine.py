@@ -54,6 +54,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from jax.experimental.shard_map import shard_map
 
 from . import channel as ch
+from . import tracing
 
 Pytree = Any
 
@@ -178,6 +179,15 @@ class CapacityPlanner:
 # The engine
 # ---------------------------------------------------------------------------
 
+def _avals(args: Pytree) -> Pytree:
+    """Shape/dtype stand-ins of a round's arguments (``last_exec``): taken
+    before the first call, since donation invalidates the state buffers,
+    and never the arrays themselves, which would keep a round's states and
+    payloads alive between steps."""
+    return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        jnp.asarray(x).shape, jnp.asarray(x).dtype), args)
+
+
 def _as_int(x) -> int:
     """Host-resolve a stat entry: a scalar-ish array, or ``(array, idx)``
     kept lazy so the hot path never slices a sharded array eagerly."""
@@ -202,7 +212,8 @@ class DelegationEngine:
         self._trusts: Dict[int, Any] = {}
         self._next_token = 0
         self._dirty: List[int] = []
-        self._cache: Dict[Any, Tuple[Callable, Callable]] = {}
+        # key -> (jitted program, raw fn, resp_bytes_saved, its avals)
+        self._cache: Dict[Any, Tuple[Callable, Callable, int, Any]] = {}
         self.planner = planner if planner is not None else CapacityPlanner()
         # donate the state buffers into each round's jitted program: the old
         # state is dead the moment the round commits (``trust._state`` is
@@ -225,13 +236,15 @@ class DelegationEngine:
         self._impl_events: Dict[Any, Tuple[str, ...]] = {}
         self._stats_owner: Dict[str, int] = {}
         self.last_step_info: Dict[str, Any] = {"fused": [], "solo": []}
-        # (unjitted fused fn, aval-shaped args) — jaxpr inspection in tests
+        # (unjitted fused fn, aval-shaped args) of the last round's program
+        # — jaxpr inspection in tests; fixed per program, so kept in _cache
         self.last_exec = None
         # -- resilience (DESIGN.md §14) ---------------------------------
         # monotonic wave id per step() dispatch: failure schedules key on
         # it, snapshot manifests record it, replays get FRESH ids
         self.wave_counter = 0
         self._current_wave = -1
+        self._stepping = False      # in step(): spans read _current_wave
         self.injector = None            # EngineFailureInjector, if installed
         self.dead_shards: set = set()
         self.recovery = {"restores": 0, "replayed_rounds": 0,
@@ -330,6 +343,11 @@ class DelegationEngine:
             trust._mux_sig = sig
         return sig
 
+    def span_wave(self) -> int:
+        """The wave id a host span carries: the wave ``step()`` is running,
+        or outside it (a synchronous apply) the id the next step takes."""
+        return self._current_wave if self._stepping else self.wave_counter
+
     def step(self, sync: bool = True) -> Optional[Dict[str, Dict[str, int]]]:
         """Flush every pending batch in as few channel rounds as possible.
 
@@ -341,6 +359,15 @@ class DelegationEngine:
         pay.  ``sync=False`` dispatches the round asynchronously and
         returns ``None``; call ``last_stats()`` later (after consuming the
         responses) for the same numbers."""
+        with tracing.span(tracing.STEP, self.wave_counter):
+            self._stepping = True
+            try:
+                self._step()
+            finally:
+                self._stepping = False
+        return self.last_stats() if sync else None
+
+    def _step(self) -> None:
         self._prune()
         pending_trusts = []
         for tok in list(self._dirty):
@@ -383,7 +410,6 @@ class DelegationEngine:
                 if t._pending:
                     self.notify(t)
             raise
-        return self.last_stats() if sync else None
 
     # -- solo fast path (the pre-engine per-Trust program) ------------------
     def run_solo(self, trust, batches, capacity: Optional[int] = None):
@@ -413,22 +439,22 @@ class DelegationEngine:
                trust.batch_signature([b[0] for b in batches], sizes,
                                      [b[2] for b in batches]),
                cfg.capacity, cfg.overflow_capacity, cfg.fuse_sig())
-        if key not in self._cache:
-            fn, saved = _build_solo(trust, batches, cfg)
-            self._cache[key] = (self._jit(fn), fn, saved)
-        jitted, raw, _saved = self._cache[key]
         args = (trust._state, [b[1] for b in batches],
                 [b[2] for b in batches])
-        # jaxpr-inspection hook (shape/dtype avals only), matching _run_mux;
-        # captured BEFORE the call — donation invalidates the state buffers
-        self.last_exec = (raw, jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(jnp.asarray(x).shape,
-                                           jnp.asarray(x).dtype), args))
-        # impl events fire at trace time (first call per cache entry): pin
-        # them to the program so later cache-hit steps still report them
-        with ch.collect_impl_events() as impl_events:
-            (new_state, resps, rounds, residual, demand,
-             combined, req_saved) = jitted(*args)
+        miss = key not in self._cache
+        with tracing.span(tracing.BUILD if miss else tracing.LAUNCH,
+                          self.span_wave()):
+            if miss:
+                fn, saved = _build_solo(trust, batches, cfg)
+                self._cache[key] = (self._jit(fn), fn, saved, _avals(args))
+            jitted, raw, saved, avals = self._cache[key]
+            self.last_exec = (raw, avals)
+            # impl events fire at trace time (first call per cache entry):
+            # pin them to the program so later cache-hit steps still report
+            # them
+            with ch.collect_impl_events() as impl_events:
+                (new_state, resps, rounds, residual, demand,
+                 combined, req_saved) = jitted(*args)
         if impl_events:
             self._impl_events[key] = tuple(impl_events)
         # post-dispatch failure injection (drop/tear): fires BEFORE the
@@ -445,7 +471,7 @@ class DelegationEngine:
         # per-trust stats print) can always read them
         self._last_step_stats[self._stats_key(trust)] = {
             "rounds": rounds, "residual": residual, "demand_max": demand,
-            "resp_bytes_saved": self._cache[key][2],
+            "resp_bytes_saved": saved,
             "rows_combined": combined, "req_bytes_saved": req_saved,
             "impl_fallback": len(self._impl_events.get(key, ()))}
         return list(resps)
@@ -501,23 +527,22 @@ class DelegationEngine:
                                            [b[2] for b in tb])
                          for t, tb, sz in zip(trusts, batches, sizes)),
                    cfg.capacity, cfg.overflow_capacity, cfg.fuse_sig())
-            if key not in self._cache:
-                fn, saved = _build_mux(trusts, batches, cfg)
-                self._cache[key] = (self._jit(fn), fn, saved)
-            jitted, raw, saved = self._cache[key]
             states = tuple(t._state for t in trusts)
             dsts = [[b[1] for b in tb] for tb in batches]
             payloads = [[b[2] for b in tb] for tb in batches]
-            # aval capture must precede the call: donation invalidates the
-            # state buffers the moment the program consumes them
-            aval_args = jax.tree.map(
-                lambda x: jax.ShapeDtypeStruct(jnp.asarray(x).shape,
-                                               jnp.asarray(x).dtype),
-                (states, dsts, payloads))
-            with ch.collect_impl_events() as impl_events:
-                (new_states, resps, rounds, residual_pt, demand_pt,
-                 demand_merged, combined, req_saved) = \
-                    jitted(states, dsts, payloads)
+            miss = key not in self._cache
+            with tracing.span(tracing.BUILD if miss else tracing.LAUNCH,
+                              self.span_wave()):
+                if miss:
+                    fn, saved = _build_mux(trusts, batches, cfg)
+                    self._cache[key] = (self._jit(fn), fn, saved,
+                                        _avals((states, dsts, payloads)))
+                jitted, raw, saved, avals = self._cache[key]
+                self.last_exec = (raw, avals)
+                with ch.collect_impl_events() as impl_events:
+                    (new_states, resps, rounds, residual_pt, demand_pt,
+                     demand_merged, combined, req_saved) = \
+                        jitted(states, dsts, payloads)
             if impl_events:
                 self._impl_events[key] = tuple(impl_events)
             # post-dispatch failure injection (drop/tear) BEFORE any state
@@ -531,10 +556,6 @@ class DelegationEngine:
                 t._pending = pend + t._pending
                 self.notify(t)
             raise
-        # jaxpr-inspection hook: keep only shape/dtype avals, not the real
-        # arrays — holding the previous round's states/payloads alive would
-        # double the engine's memory footprint between steps
-        self.last_exec = (raw, aval_args)
         self.rounds_dispatched += 1
         if self._replaying:
             self.recovery["replayed_rounds"] += 1
@@ -865,7 +886,7 @@ def _build_solo(trust, batches, cfg: ch.ChannelConfig):
 
     single_op = len(set(op_ids)) == 1
 
-    def fused(state, dsts, payloads):
+    def fuse(dsts, payloads):
         # concat batches, tag each row with its op id; a single-op round
         # skips the lane (it would be a constant column on the wire)
         dst = jnp.concatenate(dsts, 0)
@@ -910,7 +931,11 @@ def _build_solo(trust, batches, cfg: ch.ChannelConfig):
             if span_col is not None:
                 span_col = jnp.concatenate(
                     [span_col, jnp.full((pad,), -1, jnp.int32)], 0)
+        return dst, rows, span_col
 
+    def fused(state, dsts, payloads):
+        with tracing.scope(tracing.FUSE):
+            dst, rows, span_col = fuse(dsts, payloads)
         # any defer config routes through the drain engine so the
         # rounds/residual telemetry is truthful even at max_rounds=1
         drain = cfg.overflow == "defer"
@@ -955,10 +980,11 @@ def _build_solo(trust, batches, cfg: ch.ChannelConfig):
         # split the fused responses back per batch INSIDE the program (host-
         # side slicing of sharded arrays would pay one dispatch per leaf)
         resps, off = [], 0
-        for n in batch_sizes:
-            resps.append(jax.tree.map(lambda l, o=off, m=n: l[o:o + m],
-                                      resp))
-            off += n
+        with tracing.scope(tracing.RESPOND):
+            for n in batch_sizes:
+                resps.append(jax.tree.map(lambda l, o=off, m=n: l[o:o + m],
+                                          resp))
+                off += n
         return (new_state, tuple(resps), rounds, residual, demand,
                 combined, req_saved)
 
@@ -1102,7 +1128,7 @@ def _build_mux(trusts, batches, cfg: ch.ChannelConfig) -> Callable:
     need_op = any(len(active) > 1 for _ops, active in tables)
     need_trust_on_wire = (not strided) or cfg.local_shortcut
 
-    def fused(states, dsts, payloads):
+    def fuse(dsts, payloads):
         flat = []   # (tid, oid, dst, payload) in (trust, batch) order
         for tid, (tb_d, tb_p, tb) in enumerate(zip(dsts, payloads, batches)):
             for (oid, _d0, _p0), d, p in zip(tb, tb_d, tb_p):
@@ -1164,7 +1190,11 @@ def _build_mux(trusts, batches, cfg: ch.ChannelConfig) -> Callable:
             if span_col is not None:
                 span_col = jnp.concatenate(
                     [span_col, jnp.full((pad,), -1, jnp.int32)], 0)
+        return dst, rows, tid_col, span_col
 
+    def fused(states, dsts, payloads):
+        with tracing.scope(tracing.FUSE):
+            dst, rows, tid_col, span_col = fuse(dsts, payloads)
         drain = cfg.overflow == "defer"
 
         def shard_fn(states_l, dst_l, rows_l, tid_l, *extra):
@@ -1223,11 +1253,12 @@ def _build_mux(trusts, batches, cfg: ch.ChannelConfig) -> Callable:
         # slice every (trust, batch) span back out INSIDE the program (host-
         # side slicing of sharded arrays would pay one dispatch per leaf)
         out_resps = []
-        for tid, tb_spans in enumerate(spans):
-            src = resp if merged_resp else resp[tid]
-            out_resps.append(tuple(
-                jax.tree.map(lambda l, o=o, m=m: l[o:o + m], src)
-                for (o, m) in tb_spans))
+        with tracing.scope(tracing.RESPOND):
+            for tid, tb_spans in enumerate(spans):
+                src = resp if merged_resp else resp[tid]
+                out_resps.append(tuple(
+                    jax.tree.map(lambda l, o=o, m=m: l[o:o + m], src)
+                    for (o, m) in tb_spans))
         return (new_states, tuple(out_resps), rounds, res_pt,
                 demand_pt, demand_merged, combined, req_saved)
 

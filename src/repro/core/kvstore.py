@@ -28,7 +28,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from .channel import DelegatedOp, Received
 from .opspec import Combine, Field, OpSpec, TrustSchema
 from .trust import Trust, TrusteeGroup
-from . import routing
+from . import routing, tracing
 
 Pytree = Any
 
@@ -126,46 +126,55 @@ class KVTableServe:
             K-row gather commits them — the value rows never ride an N-row
             scatter (that width is what made per-row scatters the §9 hot
             spot for wide values)."""
-            winner = jnp.full((n_local + 1,), -1, jnp.int32) \
-                .at[jnp.where(win, idx, n_local)].set(pos, mode="drop")[
-                    :n_local]
-            has = (winner >= 0)[:, None]
-            return jnp.where(has, value[jnp.clip(winner, 0, None)], table)
+            with tracing.scope(tracing.KV_COMMIT):
+                winner = jnp.full((n_local + 1,), -1, jnp.int32) \
+                    .at[jnp.where(win, idx, n_local)].set(pos, mode="drop")[
+                        :n_local]
+                has = (winner >= 0)[:, None]
+                return jnp.where(has, value[jnp.clip(winner, 0, None)],
+                                 table)
 
         resp_value = jnp.zeros((n, self.value_width), table.dtype)
         # GET — reads the round-entry table
         if "get" in lanes:
-            m = lanes["get"]
-            resp_value = resp_value + _mask(table[jnp.where(m, idx, 0)], m)
+            with tracing.scope(tracing.KV_GET):
+                m = lanes["get"]
+                resp_value = resp_value + _mask(table[jnp.where(m, idx, 0)],
+                                                m)
         # PUT — segment-last rows commit (request coords: one compare)
         if "put" in lanes:
-            m = lanes["put"]
-            table = commit(table, m & (g.inv == g.seg_end_row - 1))
+            with tracing.scope(tracing.KV_PUT):
+                m = lanes["put"]
+                table = commit(table, m & (g.inv == g.seg_end_row - 1))
         # ADD — prior = segment-exclusive prefix sum of the sorted deltas
         if "add" in lanes:
-            m = lanes["add"]
-            delta = _mask(value, m)
-            delta_s = jnp.take(delta, g.order, axis=0)
-            excl = jnp.cumsum(delta_s, axis=0) - delta_s
-            prior = jnp.take(excl - excl[g.seg_start], g.inv, axis=0)
-            base = table[jnp.where(m, idx, 0)]
-            resp_value = resp_value + _mask(base + prior, m)
-            table = table.at[jnp.where(m, idx, n_local)].add(
-                delta, mode="drop")
+            with tracing.scope(tracing.KV_ADD):
+                m = lanes["add"]
+                delta = _mask(value, m)
+                delta_s = jnp.take(delta, g.order, axis=0)
+                excl = jnp.cumsum(delta_s, axis=0) - delta_s
+                prior = jnp.take(excl - excl[g.seg_start], g.inv, axis=0)
+                base = table[jnp.where(m, idx, 0)]
+                resp_value = resp_value + _mask(base + prior, m)
+                with tracing.scope(tracing.KV_COMMIT):
+                    table = table.at[jnp.where(m, idx, n_local)].add(
+                        delta, mode="drop")
         # CAS — compare against the post-ADD table; the LAST matching row
         # of each segment commits (running max of matching positions, read
         # at the segment end, aliases no earlier segment: positions grow
         # globally)
         if "cas" in lanes:
-            m = lanes["cas"]
-            cur = table[jnp.where(m, idx, 0)]
-            ok = m & jnp.all(cur == rows["expect"], axis=-1)
-            ok_s = jnp.take(ok, g.order)
-            run = jax.lax.cummax(jnp.where(ok_s, pos, -1))
-            write_s = (pos == run[jnp.clip(g.seg_end - 1, 0, n - 1)]) & ok_s
-            table = commit(table, jnp.take(write_s, g.inv))
-            resp_value = resp_value + _mask(cur, m)
-            flag = ok.astype(jnp.int32)
+            with tracing.scope(tracing.KV_CAS):
+                m = lanes["cas"]
+                cur = table[jnp.where(m, idx, 0)]
+                ok = m & jnp.all(cur == rows["expect"], axis=-1)
+                ok_s = jnp.take(ok, g.order)
+                run = jax.lax.cummax(jnp.where(ok_s, pos, -1))
+                write_s = (pos == run[jnp.clip(g.seg_end - 1, 0, n - 1)]) \
+                    & ok_s
+                table = commit(table, jnp.take(write_s, g.inv))
+                resp_value = resp_value + _mask(cur, m)
+                flag = ok.astype(jnp.int32)
         else:
             flag = jnp.zeros((n,), jnp.int32)
         return {**state, "table": table}, \
@@ -216,10 +225,13 @@ class KVTableServe:
         br = cfg.serve_block_rows if cfg is not None else 256
         bk = cfg.serve_block_keys if cfg is not None else 512
         meta = g.tile_meta(block_rows=br)
-        new_table, val_s, flag_s = kops.delegation_serve(
-            table, srt(keys), srt(lane), srt(value.astype(jnp.float32)),
-            srt(expect.astype(jnp.float32)), g.seg_start, meta.cont,
-            br=meta.block_rows, bk=bk, interpret=interp)
+        # one kernel serves every lane and writes the table back: all of it
+        # reads as the commit
+        with tracing.scope(tracing.KV_COMMIT):
+            new_table, val_s, flag_s = kops.delegation_serve(
+                table, srt(keys), srt(lane), srt(value.astype(jnp.float32)),
+                srt(expect.astype(jnp.float32)), g.seg_start, meta.cont,
+                br=meta.block_rows, bk=bk, interpret=interp)
         unsrt = lambda x: jnp.take(x, g.inv, axis=0)
         return {**state, "table": new_table.astype(table.dtype)}, \
                {"value": unsrt(val_s).astype(table.dtype),

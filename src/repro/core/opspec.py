@@ -47,6 +47,8 @@ from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from . import tracing
+
 Pytree = Any
 
 
@@ -512,20 +514,27 @@ class OpHandle:
     def spec(self) -> OpSpec:
         return self._spec
 
-    def _bind(self, args, kwargs, where):
-        payload = self._spec.bind(args, kwargs)
-        dst = self._trust.schema.dst_for(payload, self._trust.n_trustees,
-                                         where)
+    def _bind(self, args, kwargs, where, wave: int):
+        with tracing.span(tracing.BIND, wave):
+            payload = self._spec.bind(args, kwargs)
+        with tracing.span(tracing.ROUTE, wave):
+            dst = self._trust.schema.dst_for(payload, self._trust.n_trustees,
+                                             where)
         return dst, payload
 
     def __call__(self, *args, where=None, capacity=None, **kwargs) -> Pytree:
-        dst, payload = self._bind(args, kwargs, where)
+        wave = self._trust.session.span_wave()
+        with tracing.span(tracing.SUBMIT, wave):
+            dst, payload = self._bind(args, kwargs, where, wave)
         return self._trust._apply_validated(self._op_id, dst, payload,
                                             capacity)
 
     def then(self, *args, where=None, then=None, **kwargs):
-        dst, payload = self._bind(args, kwargs, where)
-        return self._trust._submit_validated(self._op_id, dst, payload, then)
+        wave = self._trust.session.span_wave()
+        with tracing.span(tracing.SUBMIT, wave):
+            dst, payload = self._bind(args, kwargs, where, wave)
+            return self._trust._submit_validated(self._op_id, dst, payload,
+                                                 then)
 
     def __repr__(self):
         return (f"<op {self._trust.name}.{self._spec.name}"

@@ -45,6 +45,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .channel import ChannelConfig, DelegatedOp
 from .opspec import OpNamespace, TrustSchema
+from . import tracing
 
 Pytree = Any
 
@@ -430,13 +431,15 @@ class Trust:
         self.session.notify(self)
         return fut
 
-    def _shim(self, op: str, payload: Pytree) -> Tuple[int, Pytree]:
+    def _shim(self, op: str, payload: Pytree,
+              wave: int) -> Tuple[int, Pytree]:
         """The stringly entry points' validation step: an unknown op name
         raises ``KeyError`` on both the schema'd and schema-less paths
         (the pre-schema behavior); schema'd trusts additionally validate
         and coerce the payload dict against the OpSpec (``SchemaError``)."""
         if self.schema is not None:
-            payload = self.schema.bind_payload(op, payload)
+            with tracing.span(tracing.BIND, wave):
+                payload = self.schema.bind_payload(op, payload)
         elif op not in self.op_index:
             raise KeyError(
                 f"trust {self.name!r} has no op {op!r} "
@@ -448,7 +451,9 @@ class Trust:
         """Synchronous delegation (paper apply()): blocks for the response.
         Stringly shim over the typed path — prefer ``trust.op.<name>(...)``
         on schema'd trusts (same program, routed and validated)."""
-        op_id, payload = self._shim(op, payload)
+        wave = self.session.span_wave()
+        with tracing.span(tracing.SUBMIT, wave):
+            op_id, payload = self._shim(op, payload, wave)
         return self._apply_validated(op_id, dst, payload, capacity)
 
     def submit(self, op: str, dst: jax.Array, payload: Pytree,
@@ -458,8 +463,10 @@ class Trust:
         round (request batching, §5.3) — across every registered Trust when
         the round runs through the session engine.  Stringly shim — prefer
         ``trust.op.<name>.then(...)`` on schema'd trusts."""
-        op_id, payload = self._shim(op, payload)
-        return self._submit_validated(op_id, dst, payload, then)
+        wave = self.session.span_wave()
+        with tracing.span(tracing.SUBMIT, wave):
+            op_id, payload = self._shim(op, payload, wave)
+            return self._submit_validated(op_id, dst, payload, then)
 
     def flush(self, capacity: Optional[int] = None) -> None:
         """Run this trust's queued batches as ONE solo channel round."""

@@ -55,12 +55,17 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 
+from ..core import tracing
+
 Pytree = Any
 
 
 @dataclass
 class WaveHandle:
-    """One dispatched engine round and the bookkeeping to consume it."""
+    """One dispatched engine round and the bookkeeping to consume it.
+    ``wave_id`` counts StreamingDriver's waves; ``engine_wave`` is the engine's
+    id of the round (-1 when the dispatch ran none), which the engine's
+    host spans carry too."""
     wave_id: int
     outputs: Any = None              # pytree of arrays / TrustFutures
     rows: int = 0
@@ -69,6 +74,7 @@ class WaveHandle:
     dispatched_at: float = 0.0
     consumed_at: float = -1.0
     users: Optional[Dict[Any, int]] = None   # per-user row breakdown
+    engine_wave: int = -1
 
     @property
     def wave_latency_s(self) -> float:
@@ -161,7 +167,8 @@ class StreamingDriver:
     the wave (the load generator owns the arrival clock).  ``events``
     records ``("dispatch", k)`` / ``("consume", k)`` in host order so
     tests can assert overlap actually happened (wave k+1 dispatched before
-    wave k consumed)."""
+    wave k consumed).  A consumed wave's handle is not kept: ``stats()``
+    reads running counts."""
 
     def __init__(self, session, depth: int = 1,
                  admission: Optional[AdmissionControl] = None,
@@ -178,7 +185,11 @@ class StreamingDriver:
         self._inflight: deque = deque()
         self._next_wave = 0
         self.events: List[Tuple[str, int]] = []
-        self.consumed: List[WaveHandle] = []
+        # running counts over the consumed waves, for stats()
+        self._n_consumed = 0
+        self._rows_consumed = 0
+        self._n_overlapped = 0
+        self._latency_sum_s = 0.0
         self._ema_cache: Dict[Any, float] = {}
 
     # -- pipeline core ------------------------------------------------------
@@ -193,7 +204,10 @@ class StreamingDriver:
                        rids=tuple(rids), on_consume=on_consume,
                        dispatched_at=time.perf_counter(), users=users)
         self._next_wave += 1
+        before = self.session.wave_counter
         self.session.step(sync=False)
+        if self.session.wave_counter > before:
+            h.engine_wave = before
         self._inflight.append(h)
         self.events.append(("dispatch", h.wave_id))
         while len(self._inflight) > self.depth:
@@ -227,30 +241,41 @@ class StreamingDriver:
 
     def _consume_oldest(self) -> WaveHandle:
         h = self._inflight.popleft()
-        if h.outputs is not None:
-            jax.block_until_ready(_concrete(h.outputs))
-        h.consumed_at = time.perf_counter()
-        self.events.append(("consume", h.wave_id))
-        if self.admission is not None:
-            self.admission.release(h.rows, h.users)
-        # refresh the EMA cache for wave_budget() only at QUIESCE points:
-        # planner.observe() overwrites the staged demand scalar at every
-        # dispatch, so with waves still in flight the staged value belongs
-        # to an unfinished round and resolving it would host-sync on it —
-        # the stall this driver exists to avoid
-        if not self._inflight:
-            for sig in list(self.session.planner._staged):
-                self._ema_cache[sig] = self.session.planner.ema(sig)
-        if h.on_consume is not None:
-            h.on_consume(h)
-        self.consumed.append(h)
+        with tracing.span(tracing.CONSUME, h.engine_wave):
+            if h.outputs is not None:
+                with tracing.span(tracing.WAIT, h.engine_wave):
+                    jax.block_until_ready(_concrete(h.outputs))
+            h.consumed_at = time.perf_counter()
+            self.events.append(("consume", h.wave_id))
+            # it overlapped if a later wave was dispatched before it was
+            # consumed
+            overlapped = self._next_wave > h.wave_id + 1
+            if self.admission is not None:
+                self.admission.release(h.rows, h.users)
+            # refresh the EMA cache for wave_budget() only at QUIESCE
+            # points: planner.observe() overwrites the staged demand scalar
+            # at every dispatch, so with waves still in flight the staged
+            # value belongs to an unfinished round and resolving it would
+            # host-sync on it — the stall this driver exists to avoid
+            if not self._inflight:
+                for sig in list(self.session.planner._staged):
+                    self._ema_cache[sig] = self.session.planner.ema(sig)
+            if h.on_consume is not None:
+                with tracing.span(tracing.CALLBACK, h.engine_wave):
+                    h.on_consume(h)
+        self._n_consumed += 1
+        self._rows_consumed += h.rows
+        self._n_overlapped += overlapped
+        self._latency_sum_s += h.wave_latency_s
         return h
 
     def drain(self) -> List[WaveHandle]:
-        """Consume every wave still in flight (end of stream)."""
+        """Consume every wave still in flight (end of stream); returns
+        the waves this call consumed."""
+        done = []
         while self._inflight:
-            self._consume_oldest()
-        return self.consumed
+            done.append(self._consume_oldest())
+        return done
 
     @property
     def inflight(self) -> int:
@@ -326,22 +351,12 @@ class StreamingDriver:
     # -- telemetry ----------------------------------------------------------
     def stats(self) -> Dict[str, Any]:
         """Host-side pipeline telemetry over the consumed waves."""
-        waves = self.consumed
-        lat = [h.wave_latency_s for h in waves if h.consumed_at >= 0]
-        # a wave overlapped if some LATER wave was dispatched before it was
-        # consumed — count from the event log
-        overlapped = 0
-        for kind, wid in self.events:
-            if kind != "consume":
-                continue
-            i = self.events.index(("consume", wid))
-            if any(k == "dispatch" and w > wid for k, w in self.events[:i]):
-                overlapped += 1
-        out = {"waves": len(waves),
-               "rows": sum(h.rows for h in waves),
+        n = self._n_consumed
+        out = {"waves": n,
+               "rows": self._rows_consumed,
                "depth": self.depth,
-               "overlapped_waves": overlapped,
-               "mean_wave_latency_s": (sum(lat) / len(lat)) if lat else 0.0}
+               "overlapped_waves": self._n_overlapped,
+               "mean_wave_latency_s": self._latency_sum_s / n if n else 0.0}
         if self.admission is not None:
             out["admitted_rows"] = self.admission.admitted
             out["admission_refusals"] = self.admission.refused
