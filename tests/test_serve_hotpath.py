@@ -10,6 +10,7 @@ holds the in-process unit layer:
     the dropped mask set, never wrap-around garbage from another slot
   * serve_optable's up-front response-structure mismatch error
   * kernel-vs-grouped-ref bit-identity on random KV batches
+  * grouped ref rounds vs the sequential oracle, one or two trustees
   * response elision: a PUT-only round reports saved bytes and stays exact
 """
 import jax
@@ -159,6 +160,98 @@ def test_serve_kernel_engages():
     assert "pallas_call" in jaxpr, "fused serve kernel did not engage"
     serve_ref = serve_optable(ops, active_ids=(0, 1, 2, 3), serve_impl="ref")
     assert "pallas_call" not in str(jax.make_jaxpr(serve_ref)(state, received))
+
+
+# ---------------------------------------------------------------------------
+# Grouped ref serve vs the sequential oracle, trustee by trustee
+# ---------------------------------------------------------------------------
+
+GET, PUT, ADD, CAS = range(4)
+
+
+def _edge_wave(rng, n_keys, t, table):
+    """One wave of (op, key, value, expect) rows: random rows around the
+    cases the in-place commit must get right — duplicate-key PUTs, PUT
+    then CAS on one key (one CAS expecting the PUT's value, one the
+    round-entry row), a CAS that fails, GETs of keys the wave writes, and
+    writes to the last local row of every trustee."""
+    last = [n_keys - t + i for i in range(t)]     # local row n_local - 1
+    hot = int(rng.integers(0, n_keys - t))
+    v = lambda: rng.integers(0, 9, 2).astype(np.float32)
+    rows = [(GET, hot, v(), v()), (PUT, hot, v(), v()),
+            (GET, last[0], v(), v()), (PUT, hot, v(), v())]
+    put_hot = rows[-1][2]
+    rows += [(CAS, hot, v(), put_hot.copy()),
+             (CAS, hot, v(), table[hot].copy()),
+             (CAS, last[-1], v(), table[last[-1]] + 1)]
+    for k in last:
+        rows += [(PUT, k, v(), v()), (GET, k, v(), v()), (PUT, k, v(), v())]
+    for _ in range(40):
+        op = int(rng.integers(0, 4))
+        k = int(rng.integers(0, n_keys))
+        exp = table[k].copy() if rng.random() < 0.5 else v()
+        rows.append((op, k, v(), exp))
+    rng.shuffle(rows)
+    return rows
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("n_trustees", [1, 2])
+def test_ref_serve_rounds_match_sequential_reference(seed, n_trustees):
+    """``serve_impl="ref"`` rounds, served trustee by trustee on each
+    trustee's owner-major slice with the store's state donated, against
+    ``SequentialKVReference`` phase by phase (GET at round entry, then
+    PUT, ADD, CAS in request order): tables and responses bit for bit."""
+    rng = np.random.default_rng(seed)
+    n_keys, vw, t = 24, 2, n_trustees
+    n_local, width = n_keys // t, 96
+    ops = make_kv_ops(t, vw)
+    serve = jax.jit(serve_optable(ops, active_ids=(GET, PUT, ADD, CAS),
+                                  serve_impl="ref"), donate_argnums=(0,))
+    init = rng.integers(0, 9, (n_keys, vw)).astype(np.float32)
+    ref = SequentialKVReference(n_keys, vw)
+    ref.prefill(init)
+    local = [init[i::t].copy() for i in range(t)]   # key k at row k // t
+    for _ in range(4):
+        wave = _edge_wave(rng, n_keys, t, ref.dump())
+        op = np.array([r[0] for r in wave], np.int16)
+        key = np.array([r[1] for r in wave], np.int32)
+        val = np.stack([r[2] for r in wave])
+        exp = np.stack([r[3] for r in wave])
+        # the oracle, phase by phase over the wave's rows in request order
+        want_val = np.zeros_like(val)
+        want_flag = np.zeros(len(wave), np.int32)
+        sel = lambda o: np.where(op == o, key, -1)
+        want_val += ref.get(sel(GET))
+        ref.put(sel(PUT), val)
+        want_val += ref.add(sel(ADD), val)
+        flag, old = ref.cas(sel(CAS), exp, val)
+        want_val += old
+        want_flag += flag
+        got_val = np.zeros_like(val)
+        got_flag = np.zeros(len(wave), np.int32)
+        for i in range(t):
+            at = np.flatnonzero(key % t == i)
+            pad = width - len(at)
+            valid = np.r_[np.ones(len(at), bool), np.zeros(pad, bool)]
+            take = lambda x: np.concatenate(
+                [x[at], np.zeros((pad,) + x.shape[1:], x.dtype)])
+            rows = {"op": jnp.asarray(take(op)), "key": jnp.asarray(take(key)),
+                    "value": jnp.asarray(take(val)),
+                    "expect": jnp.asarray(take(exp))}
+            received = Received(rows, jnp.asarray(valid),
+                                jnp.zeros((width,), jnp.int32))
+            state, resp = serve({"table": jnp.asarray(local[i])}, received)
+            local[i] = np.asarray(state["table"])
+            assert local[i].shape == (n_local, vw)
+            got_val[at] = np.asarray(resp["value"])[:len(at)]
+            got_flag[at] = np.asarray(resp["flag"])[:len(at)]
+        table = np.zeros_like(init)
+        for i in range(t):
+            table[i::t] = local[i]
+        assert np.array_equal(table, ref.dump()), "table differs"
+        assert np.array_equal(got_val, want_val), "response values differ"
+        assert np.array_equal(got_flag, want_flag), "CAS flags differ"
 
 
 # ---------------------------------------------------------------------------
