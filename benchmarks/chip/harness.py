@@ -17,6 +17,7 @@ traffic, and sleeps until the next arrival.
 """
 from __future__ import annotations
 
+import ctypes
 import gc
 import resource
 import sys
@@ -45,6 +46,25 @@ def log(msg: str) -> None:
     print(f"[bench] {msg}", file=sys.stderr, flush=True)
 
 
+def keep_freed_host_memory() -> None:
+    """Keep what the process frees on the host for its next allocation.
+
+    Every wave's responses arrive in fresh host arrays (62 MB a wave on
+    four chips).  By default glibc hands freed memory at the top of its
+    heap back to the kernel, and whether it can depends on where the
+    arrays the check keeps happen to lie, so some runs fault the next
+    wave's arrays in again and others do not.  Allocations of up to 32 MB
+    (glibc's largest mmap threshold) then come from the heap, and the
+    heap is trimmed only above 2 GB free."""
+    m_trim_threshold, m_mmap_threshold = -1, -3      # glibc's malloc.h
+    libc = ctypes.CDLL("libc.so.6")
+    libc.mallopt.argtypes = (ctypes.c_int, ctypes.c_int)
+    libc.mallopt.restype = ctypes.c_int
+    ok = libc.mallopt(m_mmap_threshold, 32 << 20) and \
+        libc.mallopt(m_trim_threshold, (1 << 31) - 1)
+    log(f"host allocator keeps freed memory: {bool(ok)}")
+
+
 @dataclass
 class CellSpec:
     name: str
@@ -63,6 +83,7 @@ class WaveRecord:
     arrived: Optional[range] = None   # open loop: arrival indices served
     t_dispatch: float = 0.0
     t_done: float = -1.0         # responses on the host
+    receive_s: float = 0.0       # the client's copy of them to the host
     got: Optional[List[Dict]] = None  # received fields, for kept waves
 
 
@@ -159,9 +180,10 @@ class Cell:
         table_bytes = n_pad * self.width * np.dtype(dtype).itemsize
         limit = (devices[0].memory_stats() or {}).get("bytes_limit")
         # the store makes its zero table on the default device before it
-        # shards it: where that table would crowd one chip, make it on the
-        # host
-        host = jax.devices("cpu")[0] if limit and table_bytes > limit // 2 \
+        # shards it, and on several chips it cuts every shard there too:
+        # where that would crowd one chip, make the table on the host
+        held = table_bytes * (2 if len(devices) > 1 else 1)
+        host = jax.devices("cpu")[0] if limit and held > limit // 2 \
             else None
         with jax.default_device(host):
             self.store = DelegatedKVStore(
@@ -171,12 +193,18 @@ class Cell:
                 session=TrustSession(donate_states=True), name="kv")
         t1 = time.perf_counter()
         trust = self.store.trust
+        # the store's zero table may still be on its way to the devices
+        # (from the host, above): replaced before it lands, its copies
+        # would run on into the window
+        jax.block_until_ready(trust.state())
+        t2 = time.perf_counter()
         table = initial_table(self.data, self.n_keys, self.store.t,
                               trust.trustee_state()["table"], dtype)
         trust.install_trustee_state({"table": table})
         jax.block_until_ready(trust.state())
-        log(f"store built in {t1 - t:.3f} s, initial table made on the "
-            f"device and installed in {time.perf_counter() - t1:.3f} s")
+        log(f"store built in {t1 - t:.3f} s, its zero table on the devices "
+            f"{t2 - t1:.3f} s later, initial table made on the device and "
+            f"installed in {time.perf_counter() - t2:.3f} s")
         self.ref = KVReference(
             self.n_keys, self.data, self.store.t,
             owner_last=self.mode == "shared",
@@ -274,11 +302,13 @@ class Cell:
             # waves keeps theirs for the comparison after the window
             def cb(h):
                 with ann("bench.receive"):
+                    t_r = time.perf_counter()
                     receipt = h.outputs[0]
                     got = [{f: np.asarray(fut.result()[f])
                             for f in RECEIVED_FIELDS[op]}
                            for op, fut in zip(rec.ops, receipt.futures)]
                     rec.t_done = time.perf_counter()
+                    rec.receive_s = rec.t_done - t_r
                     receipt.futures = None
                     n_received[0] += 1
                     if len(kept) < keep_cap:
@@ -427,8 +457,9 @@ class Cell:
     @staticmethod
     def log_steadiness(win, t0, t_last, u0, u1, gc_pauses) -> None:
         """What the host did in the window besides serving: the longest
-        gaps between wave dispatches, garbage collection, CPU time against
-        wall time, page faults and involuntary context switches."""
+        gaps between wave dispatches, the time the client took to receive
+        each wave's responses, garbage collection, CPU time against wall
+        time, page faults and involuntary context switches."""
         t = np.array([t0] + [r.t_dispatch for r in win])
         gaps = np.diff(t)
         top = np.argsort(gaps)[::-1][:3]
@@ -436,6 +467,13 @@ class Cell:
             f"{gaps[i] * 1e3:.1f} ms at {t[i] - t0:.2f} s" for i in top)
             + f" (median {np.median(gaps) * 1e3:.2f} ms)" if len(gaps)
             else "no wave in the window")
+        rs = np.array([r.receive_s for r in win if r.t_done >= 0])
+        if len(rs):
+            med = np.median(rs)
+            log(f"receive per wave: median {med * 1e3:.2f} ms, 90th "
+                f"percentile {np.percentile(rs, 90) * 1e3:.2f} ms, max "
+                f"{rs.max() * 1e3:.2f} ms; {int((rs > 1.25 * med).sum())} of "
+                f"{len(rs)} waves over 1.25 times the median")
         cpu = (u1.ru_utime - u0.ru_utime) + (u1.ru_stime - u0.ru_stime)
         log(f"host in the window: {t_last - t0:.3f} s wall, {cpu:.3f} s CPU,"
             f" {u1.ru_minflt - u0.ru_minflt} minor and "

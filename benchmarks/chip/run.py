@@ -168,6 +168,7 @@ def main(argv=None) -> int:
             f"{len(devices)}")
         return 2
     devices = devices[:spec.chips]
+    harness.keep_freed_host_memory()
 
     sys.path.insert(0, os.path.join(ROOT, "src"))
     from repro.runtime import enable_compile_cache

@@ -56,6 +56,19 @@ def test_window_starts_when_every_device_records():
     assert r.busy_s == pytest.approx((1000 + 200 + 1000) / 2 * 1e-9)
 
 
+@pytest.mark.parametrize("name", ["all-to-all.3", "all_to_all.62"])
+def test_all_to_all_op_counts(name):
+    """The HLO text says ``all-to-all``; the TPU names its ops
+    ``all_to_all.N``: both count in ``all_to_all_s``."""
+    raw = synthetic()
+    raw.devices = {d: [(name if n == "all-to-all.3" else n, s, t)
+                       for n, s, t in evs]
+                   for d, evs in raw.devices.items()}
+    r = tracecut.reduce(raw)
+    assert r.all_to_all_s == pytest.approx(900e-9)
+    assert dict(r.device_ops)[name] == pytest.approx(900e-9)
+
+
 def test_merge_unions_overlaps():
     import numpy as np
     iv = np.array([[5, 7], [1, 3], [2, 4], [7, 8], [10, 11]], float)
@@ -98,6 +111,8 @@ def test_read_xplane_finds_the_harness_spans(tmp_path):
      "sort.11"),
     ("%all-to-all.3 = f32[4,128]{1,0} all-to-all(f32[4,128]{1,0} %p)",
      "all-to-all.3 f32[4,128]"),
+    ("%all_to_all.62 = f32[4,8192,250]{2,1,0:T(8,128)} all-to-all(f32[4,"
+     "8192,250]{2,1,0:T(8,128)} %p)", "all_to_all.62 f32[4,8192,250]"),
     ("fusion.1", "fusion.1")])
 def test_op_name_keeps_name_and_shape(text, want):
     assert tracecut.op_name(text) == want

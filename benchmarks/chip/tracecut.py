@@ -14,7 +14,8 @@ as data (``tests/data/``).
 * idle gaps: the complement of busy inside the window, on the first
   device, each labelled with the harness span the host was in at the
   gap's middle (the innermost one, by ``SPAN_PRIORITY``).
-* all-to-all: the summed duration of ops whose name holds ``all-to-all``.
+* all-to-all: the summed duration of ops whose name holds ``all-to-all``
+  (HLO text) or ``all_to_all`` (the TPU's op names, ``all_to_all.62``).
 """
 from __future__ import annotations
 
@@ -32,7 +33,7 @@ WINDOW = SPAN + "window"
 SPAN_PRIORITY = ("wait", "receive", "submit", "idle", "dispatch", "drain")
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
-ALL_TO_ALL = "all-to-all"
+ALL_TO_ALL = re.compile(r"all[-_]to[-_]all")
 
 Event = Tuple[str, float, float]          # (name, start_ns, duration_ns)
 # a device op event is named by its HLO text: "%name = f32[..]{layout} op("
@@ -160,7 +161,7 @@ def reduce(raw: RawTrace, top: int = 10) -> TraceReading:
         for name, s, d in evs:
             if lo <= s < hi:
                 ops[name] = ops.get(name, 0.0) + d * 1e-9
-                if ALL_TO_ALL in name:
+                if ALL_TO_ALL.search(name):
                     a2a += d * 1e-9
     n_dev = len(raw.devices)
     edges = np.concatenate([[lo], first_busy.reshape(-1), [hi]])
